@@ -79,7 +79,7 @@ def run(backends: tuple[str, ...] = ("thread", "fork"),
                 restart = time.perf_counter() - t1
 
                 t2 = time.perf_counter()
-                rm.restore(verify=True)
+                rm.restore(verify="store")
                 verify = time.perf_counter() - t2
 
             row(

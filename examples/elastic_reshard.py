@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 
 from repro.checkpoint import ChunkStore
 from repro.core import ForkedCheckpointer, RestoreManager
@@ -42,7 +42,7 @@ batch = {
 
 # ---- phase 1: train 3 steps on mesh A = (data=4, model=2), checkpoint ----
 mesh_a = make_mesh((4, 2), ("data", "model"))
-with use_mesh(mesh_a):
+with jax.set_mesh(mesh_a):
     rules_a = ShardingRules(cfg=cfg, mesh=mesh_a)
     step_a, sh_a, _ = make_train_step(model, rules_a, opt, donate=False)
     params = model.init(jax.random.key(0))
@@ -59,13 +59,13 @@ with use_mesh(mesh_a):
 
 # ---- phase 2: restore onto mesh B = (data=8,) and continue ----
 mesh_b = make_mesh((8,), ("data",))
-with use_mesh(mesh_b):
+with jax.set_mesh(mesh_b):
     rules_b = ShardingRules(cfg=cfg, mesh=mesh_b)
     step_b, sh_b, _ = make_train_step(model, rules_b, opt, donate=False)
     flat_sh, _ = flatten_with_paths({"device": sh_b})
 
     restored, manifest = RestoreManager(ChunkStore(tmp)).restore(
-        sharding_for=lambda path, shape: flat_sh.get(path), verify=True
+        sharding_for=lambda path, shape: flat_sh.get(path), verify="store"
     )
     state_b = restored["device"]
     for _ in range(2):

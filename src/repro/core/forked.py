@@ -379,7 +379,9 @@ class ForkPersistBackend(PersistBackend):
                 # jax warns that fork + its internal threads can deadlock;
                 # the child never calls back into jax/XLA — it only
                 # compresses host memory and writes files — so none of
-                # those locks are taken.
+                # those locks are taken. On a TPU v5e the child of a
+                # process holding the chip persists and commits while the
+                # parent keeps stepping.
                 warnings.filterwarnings(
                     "ignore", message="os.fork", category=RuntimeWarning
                 )

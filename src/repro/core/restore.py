@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import concurrent.futures as cf
 import threading
-from typing import Any, Callable
+from typing import Any, Callable, Literal
 
 import jax
 
@@ -27,6 +27,13 @@ from repro.checkpoint.manifest import skeleton_fill, skeleton_paths
 from repro.utils.timing import Timings
 
 ShardingFor = Callable[[str, tuple[int, ...]], jax.sharding.Sharding | None]
+# what a restore checks against the digests its save recorded:
+#   "store"  — re-hash every stored chunk before reading (paper's verified
+#              mode; timer ``restore/verify``)
+#   "device" — re-digest the restored leaves where they were placed, shard
+#              by shard on their devices (the Pallas kernel on TPU), so what
+#              the next step reads is what was saved (``restore/verify_device``)
+Verify = Literal[False, "store", "device"]
 
 
 class LazyLeaves:
@@ -144,6 +151,17 @@ class RestoreManager:
     def __init__(self, store: ChunkStore, *, timings: Timings | None = None):
         self.store = store
         self.timings = timings or Timings()
+        # {"chunks": compared, "unmatched": shards} of the last "device" check
+        self.last_check: dict[str, int] | None = None
+
+    def _verify_store(self, manifest: Manifest, verify: Verify) -> None:
+        if verify not in (False, "store", "device"):
+            raise ValueError(f"verify={verify!r}: expected False, 'store' or 'device'")
+        if verify == "store":
+            from repro.checkpoint.sharded import verify_manifest
+
+            with self.timings.measure("restore/verify"):
+                verify_manifest(self.store, manifest)
 
     def available_steps(self) -> list[int]:
         from repro.checkpoint.manifest import committed_steps
@@ -180,20 +198,20 @@ class RestoreManager:
         step: int | None = None,
         sharding_for: ShardingFor | None = None,
         lazy: bool = False,
-        verify: bool = False,
+        verify: Verify = False,
     ) -> tuple[Any, Manifest]:
         """Restore the newest (or given) committed checkpoint.
 
         Returns (state, manifest); in lazy mode state is a LazyLeaves whose
-        ``as_tree()`` gives the pytree.
+        ``as_tree()`` gives the pytree. ``verify`` (see :data:`Verify`)
+        raises at the first chunk that differs from the save; a "device"
+        check needs eager mode and leaves its counts in ``last_check``.
         """
         manifest = self._pick_manifest(step)
-        if verify:
-            from repro.checkpoint.sharded import verify_manifest
-
-            with self.timings.measure("restore/verify"):
-                verify_manifest(self.store, manifest)
+        self._verify_store(manifest, verify)
         if lazy:
+            if verify == "device":
+                raise ValueError("verify='device' needs the leaves placed: restore eagerly")
             return (
                 LazyLeaves(
                     self.store, manifest, sharding_for, timings=self.timings
@@ -210,6 +228,9 @@ class RestoreManager:
                 for path, lrec in manifest.leaves.items()
             }
             state = skeleton_fill(manifest.skeleton, leaves)
+        if verify == "device":
+            with self.timings.measure("restore/verify_device"):
+                self.last_check = verify_restored_digests(state, manifest)
         return state, manifest
 
     # -- proxy restart (paper §3.4: replay allocations, push data back) ---------
@@ -219,7 +240,7 @@ class RestoreManager:
         *,
         step: int | None = None,
         sharding_for: ShardingFor | None = None,
-        verify: bool = False,
+        verify: Verify = False,
     ) -> tuple[Any, Manifest]:
         """Restore a committed image and re-create device state in a proxy.
 
@@ -250,7 +271,7 @@ class RestoreManager:
         n_hosts: int,
         host: int | None = None,
         step: int | None = None,
-        verify: bool = False,
+        verify: Verify = False,
     ) -> tuple[Any, Manifest]:
         """Re-slice a committed image across a different worker count.
 
@@ -271,12 +292,10 @@ class RestoreManager:
         from repro.checkpoint.sharded import host_slice_plan
         from repro.core.shadow import HostShardView
 
+        if verify == "device":
+            raise ValueError("an elastic restore places nothing: verify='store' only")
         manifest = self._pick_manifest(step)
-        if verify:
-            from repro.checkpoint.sharded import verify_manifest
-
-            with self.timings.measure("restore/verify"):
-                verify_manifest(self.store, manifest)
+        self._verify_store(manifest, verify)
         if host is None:
             leaves = {
                 path: restore_leaf(self.store, lrec, None)
@@ -303,3 +322,59 @@ class RestoreManager:
                     global_shape=shape, dtype=dtype,
                 )
         return skeleton_fill(manifest.skeleton, leaves), manifest
+
+
+def verify_restored_digests(state: Any, manifest: Manifest) -> dict[str, int]:
+    """Re-digest a restored state where it lives and compare it with the
+    chunk digests its manifest recorded at save time.
+
+    A jax leaf is digested shard by shard on its device (the
+    ``kernels.ops.chunk_digests`` dispatch: the Pallas kernel on TPU), a
+    host leaf with the numpy reference. A shard whose index range the
+    manifest does not hold (a resume under another layout) cannot be
+    compared chunk for chunk and is counted as ``unmatched``. Returns
+    ``{"chunks": compared, "unmatched": shards}``; raises ValueError at
+    the first chunk whose digest differs.
+    """
+    import numpy as np
+
+    from repro.core.shadow import _owned_host_shards
+    from repro.kernels.ops import chunk_digests, digests_to_u64
+    from repro.kernels.ref import chunk_digests_np
+    from repro.utils.tree import flatten_with_paths
+
+    flat, _ = flatten_with_paths(state)
+    work = []  # (path, start, stop, shard record, data)
+    unmatched = 0
+    for path, lrec in manifest.leaves.items():
+        by_range = {(tuple(s.start), tuple(s.stop)): s for s in lrec.shards}
+        for _ordinal, start, stop, data in _owned_host_shards(flat[path]):
+            srec = by_range.get((tuple(start), tuple(stop)))
+            if srec is None:
+                unmatched += 1
+            elif srec.chunks:
+                work.append((path, start, stop, srec, data))
+
+    def digest(item) -> np.ndarray:
+        data, chunks = item[4], item[3].chunks
+        # a single-chunk shard digests the same under any chunk size that
+        # covers it; round up to whole words for the kernel
+        cb = -(-chunks[0].raw_len // 4) * 4
+        if isinstance(data, jax.Array):
+            return digests_to_u64(chunk_digests(data, cb))
+        return digests_to_u64(chunk_digests_np(np.asarray(data), cb))
+
+    # each (shard shape, device) pair compiles its own digest program:
+    # a few threads overlap those compiles, bounded so that the digests'
+    # temporaries stay small next to the restored state
+    compared = 0
+    with cf.ThreadPoolExecutor(max_workers=4, thread_name_prefix="crum-verify") as pool:
+        for (path, start, stop, srec, _), got in zip(work, pool.map(digest, work)):
+            for c in srec.chunks:
+                if int(got[c.index]) != c.digest:
+                    raise ValueError(
+                        f"restored {path} {start}:{stop} chunk {c.index} "
+                        f"digest {int(got[c.index]):#x} != saved {c.digest:#x}"
+                    )
+            compared += len(srec.chunks)
+    return {"chunks": compared, "unmatched": unmatched}

@@ -42,7 +42,7 @@ from repro.checkpoint.codecs import DEFAULT_CODEC
 from repro.checkpoint.store import ChunkStore
 from repro.core.forked import CheckpointResult, ForkedCheckpointer
 from repro.core.policy import CheckpointPolicy
-from repro.core.restore import RestoreManager
+from repro.core.restore import RestoreManager, Verify
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.utils.timing import Timings
@@ -149,9 +149,10 @@ class CheckpointedTrainer:
         init_fn: Callable[[], Any],
         *,
         sharding_for=None,
-        verify: bool = False,
+        verify: Verify = False,
     ) -> tuple[Any, int]:
-        """Restore the newest committed state or build a fresh one.
+        """Restore the newest committed state or build a fresh one, checking
+        a restored one as ``verify`` says (:data:`repro.core.restore.Verify`).
 
         In proxy mode the (restored or fresh) device state is also pushed
         into a freshly-started proxy — the paper's restart protocol of

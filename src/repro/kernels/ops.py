@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the kernels package.
 
 Dispatch policy (``use_pallas``):
-  - ``"auto"``  — Pallas on TPU backends, jnp reference elsewhere (this
-                  container is CPU-only, so auto == reference here; the
-                  dry-run/roofline path intentionally lowers the jnp path).
+  - ``"auto"``  — the compiled Pallas kernel on TPU backends, the jnp
+                  reference elsewhere (CPU tests; the dry-run/roofline
+                  path intentionally lowers the jnp path).
   - ``"interpret"`` — Pallas kernel body executed by the interpreter (CPU
                   correctness validation; used by tests/kernels/).
   - ``"pallas"`` / ``"ref"`` — forced.
@@ -19,7 +19,7 @@ import numpy as np
 
 from repro.checkpoint.chunking import num_chunks
 from repro.kernels import ref as _ref
-from repro.kernels.chunk_digest import SUB_WORDS, digest_words
+from repro.kernels.chunk_digest import LANES, digest_words, padded_row_words
 from repro.kernels.flash_attention import flash_attention_pallas
 
 Dispatch = Literal["auto", "interpret", "pallas", "ref"]
@@ -35,6 +35,12 @@ def _resolve(use_pallas: Dispatch) -> str:
     return use_pallas
 
 
+def auto_dispatch() -> str:
+    """What ``use_pallas="auto"`` runs in this process: ``"pallas"`` (the
+    compiled kernel) on TPU, ``"ref"`` (the jnp reference) elsewhere."""
+    return _resolve("auto")
+
+
 # ---------------------------------------------------------------------------
 # chunk digests
 # ---------------------------------------------------------------------------
@@ -43,20 +49,22 @@ def _resolve(use_pallas: Dispatch) -> str:
 def _chunk_digests_jit(x: jax.Array, chunk_bytes: int, mode: str) -> jax.Array:
     if mode == "ref":
         return _ref.chunk_digests_jnp(x, chunk_bytes)
-    words = _ref.to_u32_words(x)
-    total_words = words.shape[0]
+    if x.dtype.itemsize == 2:
+        # 16-bit state goes to the kernel as stored, two halves per word
+        units, per_word = jax.lax.bitcast_convert_type(x.reshape(-1), jnp.uint16), 2
+    else:
+        units, per_word = _ref.to_u32_words(x), 1
+    total_words = -(-units.shape[0] // per_word)
     cw = chunk_bytes // 4
     n = num_chunks(total_words * 4, chunk_bytes)
-    sub = min(SUB_WORDS, cw)
-    row = -(-cw // sub) * sub  # pad row length to sub-block multiple
-    padded = n * row
-    if padded != total_words:
-        words = jnp.concatenate(
-            [words, jnp.zeros((padded - total_words,), jnp.uint32)]
-        )
-    words2d = words.reshape(n, row)
+    row = padded_row_words(cw)
+    if row == cw:
+        units = jnp.pad(units, (0, n * row * per_word - units.shape[0]))
+    else:  # chunks shorter than the kernel's row: pad each one
+        units = jnp.pad(units, (0, n * cw * per_word - units.shape[0]))
+        units = jnp.pad(units.reshape(n, cw * per_word), ((0, 0), (0, (row - cw) * per_word)))
     return digest_words(
-        words2d,
+        units.reshape(-1, LANES),
         chunk_words=cw,
         total_words=total_words,
         interpret=(mode == "interpret"),
@@ -73,10 +81,7 @@ def chunk_digests(
     """
     if chunk_bytes % 4:
         raise ValueError("chunk_bytes must be a multiple of 4")
-    mode = _resolve(use_pallas)
-    if mode == "ref":
-        return _chunk_digests_jit(x, chunk_bytes, "ref")
-    return _chunk_digests_jit(x, chunk_bytes, mode)
+    return _chunk_digests_jit(x, chunk_bytes, _resolve(use_pallas))
 
 
 def digests_to_u64(d: jax.Array | np.ndarray) -> np.ndarray:

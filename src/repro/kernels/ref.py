@@ -36,16 +36,26 @@ def to_u32_words(x: jax.Array) -> jax.Array:
     """Bit-reinterpret any array as a flat little-endian u32 word stream.
 
     Matches numpy's ``.view(np.uint8)`` + zero-pad + ``.view(np.uint32)``.
+    Narrow dtypes are packed from strided slices of the flat element
+    stream, never through a (n, k) array whose tiny minor dimension a TPU
+    would pad to 128 lanes.
     """
     flat = x.reshape(-1)
-    if flat.dtype.itemsize == 4:
+    size = flat.dtype.itemsize
+    if size == 4:
         return jax.lax.bitcast_convert_type(flat, jnp.uint32)
-    b = jax.lax.bitcast_convert_type(flat, jnp.uint8)  # (n, itemsize) or (n,)
-    b = b.reshape(-1)
-    pad = (-b.shape[0]) % 4
+    if size > 4:
+        b = jax.lax.bitcast_convert_type(flat, jnp.uint8).reshape(-1, 4)
+        return jax.lax.bitcast_convert_type(b, jnp.uint32)
+    per_word = 4 // size
+    u = jax.lax.bitcast_convert_type(flat, jnp.uint8 if size == 1 else jnp.uint16)
+    pad = (-u.shape[0]) % per_word
     if pad:
-        b = jnp.concatenate([b, jnp.zeros((pad,), jnp.uint8)])
-    return jax.lax.bitcast_convert_type(b.reshape(-1, 4), jnp.uint32)
+        u = jnp.concatenate([u, jnp.zeros((pad,), u.dtype)])
+    words = u[0::per_word].astype(jnp.uint32)
+    for p in range(1, per_word):
+        words = words | (u[p::per_word].astype(jnp.uint32) << jnp.uint32(8 * size * p))
+    return words
 
 
 def chunk_digests_jnp(x: jax.Array, chunk_bytes: int) -> jax.Array:

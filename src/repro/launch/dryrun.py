@@ -25,7 +25,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, list_archs
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import SHAPES, build, shape_applicable
 from repro.optim import get_optimizer
 from repro.runtime import hlo
@@ -78,7 +78,7 @@ def run_cell(
         )
 
     t0 = time.perf_counter()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             jitted, state_sh, batch_sh_fn = make_train_step(
                 model, rules, get_optimizer(cfg.optimizer, 1e-4)

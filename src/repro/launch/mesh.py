@@ -2,44 +2,26 @@
 
 Defined as functions (never module-level constants) so importing this
 module never touches jax device state — required for the dry-run's
-XLA_FLAGS ordering and for tests that run on 1 CPU device.
-
-``AxisType`` / the ``axis_types=`` kwarg only exist in newer jax releases;
-the helpers below degrade gracefully so the same code runs on any jax
-that has ``jax.make_mesh``.
+XLA_FLAGS ordering and for tests that run on 1 CPU device. Every mesh has
+Auto axis types: shardings are propagated by the compiler from the
+in/out shardings the step builders give it.
 """
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:  # older jax: axes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """jax.make_mesh with Auto axis types where the API supports them."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_abstract_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """AbstractMesh: spec logic needs only shape+names, not real devices."""
-    if AxisType is not None:
-        return jax.sharding.AbstractMesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-        )
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-
-
-def use_mesh(mesh):
-    """Context manager entering ``mesh``: ``jax.sharding.set_mesh`` on new
-    jax, the classic ``with mesh:`` global-mesh context on older releases."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh  # Mesh is itself a context manager
+    return jax.sharding.AbstractMesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -22,6 +22,7 @@ chunks decode dirtied (cache/toks), never the clean params.
 from __future__ import annotations
 
 import argparse
+import json
 import sys
 import time
 
@@ -32,8 +33,9 @@ import numpy as np
 from repro.configs import get_config, list_archs
 from repro.core import RestoreManager
 from repro.checkpoint import ChunkStore
-from repro.launch.mesh import make_host_mesh, use_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import build
+from repro.runtime.env import device_report, enable_compile_cache, keep_off_device
 from repro.utils.tree import flatten_with_paths
 
 
@@ -58,6 +60,7 @@ def main(argv=None) -> int:
                     help="proxy data plane (default: stream when "
                          "--proxy-endpoint is given, else segment)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.device_runner == "proxy":
         return _serve_proxy(args)
@@ -65,8 +68,13 @@ def main(argv=None) -> int:
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build(cfg)
     mesh = make_host_mesh((jax.device_count(),), ("data",))
+    print(f"[serve] device {json.dumps(device_report())}", flush=True)
+    # one compiled program each for prefill and decode, not op-by-op
+    # dispatch of every layer
+    prefill = jax.jit(model.prefill, static_argnums=2) if model.prefill else None
+    decode = jax.jit(model.decode)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.perf_counter()
         if args.ckpt_dir:
             rm = RestoreManager(ChunkStore(args.ckpt_dir))
@@ -121,14 +129,14 @@ def main(argv=None) -> int:
             }
 
         t1 = time.perf_counter()
-        if model.prefill is not None:
-            logits, cache = model.prefill(params, batch, cache_len)
+        if prefill is not None:
+            logits, cache = prefill(params, batch, cache_len)
         else:
             # SSM/hybrid: prefill by decoding the prompt token-by-token
             cache = model.init_cache(B, cache_len)
             for t in range(P):
                 tok = batch["inputs"][:, t]
-                logits, cache = model.decode(params, cache, tok)
+                logits, cache = decode(params, cache, tok)
         jax.block_until_ready(logits)
         ttft = time.perf_counter() - t1
         print(f"[serve] prefill({P} tokens) -> first logits in {ttft:.3f}s")
@@ -140,15 +148,16 @@ def main(argv=None) -> int:
         t2 = time.perf_counter()
         out = [toks]
         for _ in range(G - 1):
-            logits, cache = model.decode(params, cache, toks)
+            logits, cache = decode(params, cache, toks)
             toks = sample(logits)
             out.append(toks)
         jax.block_until_ready(toks)
         dt = time.perf_counter() - t2
         print(f"[serve] generated {G-1} steps in {dt:.3f}s "
               f"({(G-1)*B/max(dt,1e-9):.1f} tok/s)")
-        first = np.asarray(out[0]).reshape(B, -1)[:, 0]
-        print(f"[serve] sample tokens: {first.tolist()}")
+        tokens = np.stack([np.asarray(t).reshape(B, -1)[:, 0] for t in out], 1)
+        print(f"[serve] tokens {json.dumps(tokens.tolist())} "
+              f"finite_logits={bool(jnp.isfinite(logits).all())}", flush=True)
     return 0
 
 
@@ -174,7 +183,9 @@ def _restored_params(args):
 
 
 def _serve_proxy(args) -> int:
-    """Decode through a (possibly remote) device proxy."""
+    """Decode through a (possibly remote) device proxy; this process
+    stays on the host CPU."""
+    keep_off_device()
     from repro.proxy import ProxyRunner, make_program
     from repro.remote.transport import endpoint_arg
     from repro.utils.tree import unflatten_from_paths

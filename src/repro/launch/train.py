@@ -6,10 +6,16 @@ and restart (examples/train_restart.py kills and resumes this loop).
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --smoke \
         --steps 20 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+
+Rerunning the command resumes from the newest committed checkpoint and
+checks the restored state against the digests the save recorded. The
+platform is whatever JAX finds (``JAX_PLATFORMS`` decides); the lines
+``[train] device {...}`` and ``[train] restore_check {...}`` say what ran.
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import sys
 import time
@@ -27,10 +33,12 @@ from repro.core import (
     list_persist_backends,
 )
 from repro.data import SyntheticBatches
-from repro.launch.mesh import make_host_mesh, make_production_mesh, use_mesh
+from repro.kernels.ops import auto_dispatch
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import build
 from repro.obs import trace as obs_trace
 from repro.optim import get_optimizer, warmup_cosine
+from repro.runtime.env import device_report, enable_compile_cache, keep_off_device
 from repro.runtime.sharding import ShardingRules
 from repro.runtime.steps import make_train_step
 from repro.utils.tree import flatten_with_paths, unflatten_from_paths
@@ -85,6 +93,7 @@ def main(argv=None) -> int:
                          "the setting; merge with "
                          "`python -m repro.obs.report DIR`)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.obs_dir:
         obs_trace.enable(args.obs_dir, "app")
@@ -99,13 +108,15 @@ def main(argv=None) -> int:
         if args.production_mesh
         else make_host_mesh((jax.device_count(),), ("data",))
     )
+    print(f"[train] device {json.dumps({**device_report(), 'digest': auto_dispatch()})}",
+          flush=True)
     rules = ShardingRules(cfg=cfg, mesh=mesh)
     optimizer = get_optimizer(
         cfg.optimizer, warmup_cosine(args.lr, 10, args.steps)
     )
 
     trainer = CheckpointedTrainer(
-        None,  # set below (needs the mesh context)
+        None,  # set below
         store_root=args.ckpt_dir,
         policy=CheckpointPolicy(interval_steps=args.ckpt_every, keep_last=2),
         codec=args.codec,
@@ -119,83 +130,110 @@ def main(argv=None) -> int:
     )
     preempt = PreemptionHandler(trainer.policy).install()
 
-    with use_mesh(mesh):
-        step_fn, state_shardings, batch_sh = make_train_step(
-            model, rules, optimizer, donate=False
+    step_fn, state_shardings, batch_sh = make_train_step(
+        model, rules, optimizer, donate=False
+    )
+    trainer.train_step = step_fn
+
+    @functools.partial(jax.jit, out_shardings=state_shardings)
+    def init_device():
+        # built where the step expects it, shard by shard
+        params = model.init(jax.random.key(0))
+        return {
+            "params": params,
+            "opt": optimizer.init(params),
+            "step": jnp.zeros((), jnp.int32),
+        }
+
+    def init_state():
+        return {
+            "device": init_device(),
+            "host": {
+                "step": np.int64(0),
+                "data": SyntheticBatches(
+                    cfg, batch=args.batch, seq_len=args.seq
+                ).state(),
+            },
+        }
+
+    def sharding_for(path, shape):
+        flat_sh, _ = flatten_with_paths(
+            {"device": state_shardings, "host": None}
         )
-        trainer.train_step = step_fn
+        return flat_sh.get(path)
 
-        def init_state():
-            params = model.init(jax.random.key(0))
-            return {
-                "device": {
-                    "params": params,
-                    "opt": optimizer.init(params),
-                    "step": jnp.zeros((), jnp.int32),
-                },
-                "host": {
-                    "step": np.int64(0),
-                    "data": SyntheticBatches(
-                        cfg, batch=args.batch, seq_len=args.seq
-                    ).state(),
-                },
-            }
+    # a resume re-digests what it placed, on the device, against the save
+    state, start = trainer.resume_or(
+        init_state, sharding_for=sharding_for, verify="device"
+    )
+    if start:
+        check = {"step": start, **trainer.restorer.last_check,
+                 "seconds": trainer.timings.totals["restore/verify_device"]}
+        print(f"[train] restore_check {json.dumps(check)}", flush=True)
+    data = SyntheticBatches.from_state(
+        cfg, batch=args.batch, seq_len=args.seq, state=state["host"]["data"]
+    )
+    print(f"[train] arch={cfg.name} start_step={start} mesh={dict(mesh.shape)}")
 
-        def sharding_for(path, shape):
-            flat_sh, _ = flatten_with_paths(
-                {"device": state_shardings, "host": None}
-            )
-            return flat_sh.get(path)
+    if args.device_capacity is not None:
+        return _run_managed(args, trainer, state, start, data, preempt)
 
-        state, start = trainer.resume_or(init_state, sharding_for=sharding_for)
-        data = SyntheticBatches.from_state(
-            cfg, batch=args.batch, seq_len=args.seq, state=state["host"]["data"]
-        )
-        print(f"[train] arch={cfg.name} start_step={start} mesh={dict(mesh.shape)}")
-
-        if args.device_capacity is not None:
-            return _run_managed(args, trainer, state, start, data, preempt)
-
-        tr = obs_trace.get()
-        step = start
-        for _ in range(args.steps - start):
-            t0 = time.perf_counter() if tr is not None else 0.0
-            batch = jax.tree.map(jnp.asarray, next(data))
-            state["device"], metrics = step_fn(state["device"], batch)
-            step += 1
-            if tr is not None:
-                tr.complete("app.step", t0, step=step)
-            state["host"]["step"] = np.int64(step)
-            state["host"]["data"] = data.state()
-            if step % args.log_every == 0 or step == args.steps:
-                print(
-                    f"[train] step={step} loss={float(metrics['loss']):.4f} "
-                    f"grad_norm={float(metrics['grad_norm']):.3f}",
-                    flush=True,
-                )
-            if trainer.policy.should_checkpoint(step):
-                r = trainer.checkpoint_now(step, state)
-                print(
-                    f"[ckpt] step={step} blocking={r.blocking_s*1e3:.1f}ms "
-                    f"(persist continues in background)",
-                    flush=True,
-                )
-            if preempt.received.is_set():
-                print("[train] preemption: checkpointing and exiting")
-                if _needs_preempt_ckpt(trainer, step):
-                    trainer.checkpoint_now(step, state)
-                break
-
-        done = trainer.finish()
-        for r in done:
+    tr = obs_trace.get()
+    step = start
+    t_log, logged = time.perf_counter(), start
+    for _ in range(args.steps - start):
+        t0 = time.perf_counter() if tr is not None else 0.0
+        batch = jax.tree.map(jnp.asarray, next(data))
+        state["device"], metrics = step_fn(state["device"], batch)
+        step += 1
+        if tr is not None:
+            tr.complete("app.step", t0, step=step)
+        state["host"]["step"] = np.int64(step)
+        state["host"]["data"] = data.state()
+        if step % args.log_every == 0 or step == args.steps:
+            loss = float(metrics["loss"])  # waits for the step
+            now = time.perf_counter()
             print(
-                f"[ckpt-done] step={r.step} blocking={r.blocking_s*1e3:.1f}ms "
-                f"persist={r.persist_s*1e3:.1f}ms written={r.chunks_written} "
-                f"reused={r.chunks_reused}"
+                f"[train] step={step} loss={loss:.4f} "
+                f"grad_norm={float(metrics['grad_norm']):.3f} "
+                f"step_s={(now - t_log) / (step - logged):.3f}",
+                flush=True,
             )
+            t_log, logged = now, step
+        if trainer.policy.should_checkpoint(step):
+            r = trainer.checkpoint_now(step, state)
+            print(
+                f"[ckpt] step={step} blocking={r.blocking_s*1e3:.1f}ms "
+                f"(persist continues in background)",
+                flush=True,
+            )
+        if preempt.received.is_set():
+            print("[train] preemption: checkpointing and exiting")
+            if _needs_preempt_ckpt(trainer, step):
+                trainer.checkpoint_now(step, state)
+            break
+
+    done = trainer.finish()
+    for r in done:
+        print(
+            f"[ckpt-done] step={r.step} blocking={r.blocking_s*1e3:.1f}ms "
+            f"persist={r.persist_s*1e3:.1f}ms written={r.chunks_written} "
+            f"reused={r.chunks_reused}"
+        )
     preempt.uninstall()
-    print(json.dumps({"final_step": step, "timings": trainer.timings.summary()}, indent=2))
+    print(json.dumps({
+        "final_step": step,
+        "peak_bytes_in_use": _peak_device_bytes(),
+        "timings": trainer.timings.summary(),
+    }, indent=2))
     return 0
+
+
+def _peak_device_bytes() -> int | None:
+    """Peak bytes in use on the busiest device, where the backend says."""
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    peaks = [s["peak_bytes_in_use"] for s in stats if s and "peak_bytes_in_use" in s]
+    return max(peaks) if peaks else None
 
 
 def _tree_nbytes(tree) -> int:
@@ -272,7 +310,10 @@ def _main_proxy(args) -> int:
     syncs the host mirror at checkpoint boundaries, and persists it with
     the same forked checkpointer. Batches are deterministic in the step
     number, which is what makes kill-replay recovery bit-identical.
+
+    This process stays on the host CPU: the proxy owns the accelerator.
     """
+    keep_off_device()
     program = {
         "name": "train_arch",
         "arch": args.arch,
@@ -323,6 +364,7 @@ def _main_proxy(args) -> int:
     state, start = trainer.resume_or(init_state)
     print(f"[train] arch={args.arch} device_runner=proxy start_step={start} "
           f"proxy_pid={trainer.runner.proxy.pid}", flush=True)
+    print(f"[train] proxy device {json.dumps(trainer.runner.device)}", flush=True)
 
     def on_metrics(step, metrics):
         loss = metrics.get("loss")
@@ -346,6 +388,7 @@ def _main_proxy(args) -> int:
             f"reused={r.chunks_reused}"
         )
     preempt.uninstall()
+    print(f"[train] app device {json.dumps(device_report())}", flush=True)
     print(json.dumps({"final_step": step, "timings": trainer.timings.summary()},
                      indent=2))
     return 0

@@ -51,21 +51,9 @@ def moe_init(key, cfg: ModelConfig, dtype) -> dict:
 
 
 def _mesh_info():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:
-        from jax._src.mesh import thread_resources
-
-        pm = thread_resources.env.physical_mesh
-        if not pm.empty:
-            return pm
-    except Exception:
-        pass
-    return None
+    """The mesh entered with ``jax.set_mesh``, if any."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def moe_apply(cfg: ModelConfig, params: dict, x: jax.Array):
@@ -172,7 +160,6 @@ def _moe_expert_parallel(cfg: ModelConfig, params: dict, x: jax.Array,
 
     Capacity is per (source device, expert): C_loc = T_loc*k/E * factor.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, D = x.shape
@@ -236,7 +223,7 @@ def _moe_expert_parallel(cfg: ModelConfig, params: dict, x: jax.Array,
         return jnp.zeros((T_loc, D), x_loc.dtype).at[token_idx].add(y_slot)
 
     tok_axes = dp + ("model",)
-    out_flat = shard_map(
+    out_flat = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -247,7 +234,7 @@ def _moe_expert_parallel(cfg: ModelConfig, params: dict, x: jax.Array,
             P("model", dp_group, None),
         ),
         out_specs=P(tok_axes, None),
-        check_rep=False,
+        check_vma=False,
     )(
         xt,
         params["router"],

@@ -60,7 +60,6 @@ class DeviceProxy:
         start_timeout_s: float = 120.0,
         op_timeout_s: float = 120.0,
         max_pipeline: int = 64,
-        jax_platforms: str | None = "cpu",
         name: str = "crum-proxy",
     ):
         self.endpoint = tuple(endpoint) if endpoint is not None else None
@@ -68,7 +67,6 @@ class DeviceProxy:
         self.start_timeout_s = start_timeout_s
         self.op_timeout_s = op_timeout_s
         self.max_pipeline = int(max_pipeline)
-        self.jax_platforms = jax_platforms
         self.name = name
         self.proc: mp.Process | None = None
         self.conn: Connection | None = None
@@ -103,7 +101,7 @@ class DeviceProxy:
         host, port = listener.getsockname()
         tr = obs_trace.get()
         cfg = ProxyServiceConfig(
-            host=host, port=port, jax_platforms=self.jax_platforms,
+            host=host, port=port,
             obs_dir=tr.obs_dir if tr is not None else None,
             obs_run=tr.run_id if tr is not None else None,
         )
@@ -234,11 +232,13 @@ class DeviceProxy:
     def send_program(self, spec: dict) -> None:
         self._call(MSG_PROGRAM, spec=spec)
 
-    def register(self, **fields: Any) -> None:
+    def register(self, **fields: Any) -> dict:
         """REGISTER with the transport/layout/paging fields the runner's
-        transport and config assembled (see protocol docstring)."""
-        self._call(MSG_REGISTER, **fields)
+        transport and config assembled (see protocol docstring). The reply
+        names the devices the proxy's program state lives on."""
+        reply = self._call(MSG_REGISTER, **fields)
         self.inflight = 0
+        return reply
 
     def upload(
         self,

@@ -116,7 +116,6 @@ class ProxyServiceConfig:
 
     host: str
     port: int
-    jax_platforms: str | None = "cpu"
     sock_timeout_s: float = 1.0
     # observability (not part of the replayable state — a respawn works
     # with or without it): where to write this incarnation's trace shard.

@@ -24,7 +24,6 @@ lives in the application's API log plus the application-side mirror.
 """
 from __future__ import annotations
 
-import os
 import socket
 import time
 from typing import Any
@@ -47,12 +46,12 @@ from repro.proxy.protocol import (
     ProxyServiceConfig,
     connect,
 )
+from repro.runtime.env import device_report, enable_compile_cache
 
 
 def proxy_entry(cfg: ProxyServiceConfig) -> int:
     """Process entry point (multiprocessing spawn target, local mode)."""
-    if cfg.jax_platforms:
-        os.environ.setdefault("JAX_PLATFORMS", cfg.jax_platforms)
+    enable_compile_cache()
     if cfg.obs_dir:
         obs_trace.enable(cfg.obs_dir, "proxy", run_id=cfg.obs_run,
                          set_env=False)
@@ -233,7 +232,7 @@ class ProxyService:
             self.dstate = init
             self.shadow.register(self.dstate)
         self.last_step = 0
-        self.conn.send(MSG_OK, op=MSG_REGISTER)
+        self.conn.send(MSG_OK, op=MSG_REGISTER, device=device_report())
 
     def _device_view(self) -> Any:
         """The device state as a host pytree (coherent, no migrations)."""

@@ -75,7 +75,6 @@ class ProxyRunner:
         sync_timeout_s: float = 120.0,
         op_timeout_s: float = 120.0,
         mp_context: str = "spawn",
-        jax_platforms: str | None = "cpu",
         fsync_log: bool = False,
         respawn_backoff_s: float = 0.05,
     ):
@@ -109,7 +108,6 @@ class ProxyRunner:
             mp_context=mp_context,
             max_pipeline=max_pipeline,
             op_timeout_s=op_timeout_s,
-            jax_platforms=jax_platforms,
         )
         self.budget = RestartBudget(max_restarts, what="device proxy")
         self.respawn_backoff_s = float(respawn_backoff_s)
@@ -120,6 +118,9 @@ class ProxyRunner:
         self._fsync_log = fsync_log
         self.log: ApiLog | None = None
         self.proxy: DeviceProxy | None = None
+        # {"platform", "kind", "count"} the current incarnation's program
+        # state lives on (its REGISTER reply); None for host-only programs
+        self.device: dict | None = None
         self.started = False
         self.last_synced_step = 0
         self.last_digest: str | None = None
@@ -472,7 +473,7 @@ class ProxyRunner:
         # separates pre-kill execution from post-respawn replay (and a
         # remote daemon learns the obs dir for runs it was not spawned by)
         tr = obs_trace.get()
-        self.proxy.register(
+        reply = self.proxy.register(
             **self.transport.register_fields(),
             chunk_bytes=self.chunk_bytes,
             device_capacity_bytes=self.device_capacity_bytes,
@@ -492,6 +493,7 @@ class ProxyRunner:
                 "ctx": self._frame_ctx(),
             },
         )
+        self.device = reply.get("device")
         self.proxy.upload(
             step=self.last_synced_step,
             payload_frames=self.transport.payload_frames(None),

@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import argparse
 import multiprocessing as mp
-import os
 import socket
 import sys
 import threading
@@ -30,7 +29,6 @@ from dataclasses import dataclass
 class ProxyHostConfig:
     bind: str = "127.0.0.1"
     port: int = 0                       # 0: OS-assigned (reported via queue)
-    jax_platforms: str | None = "cpu"
     sock_timeout_s: float = 1.0
 
 
@@ -41,11 +39,12 @@ def serve_forever(cfg: ProxyHostConfig, port_q=None, on_bound=None) -> None:
     a coordinator belongs there, never before the bind (an endpoint must
     not be advertised while nothing is accepting on it).
     """
-    if cfg.jax_platforms:
-        os.environ.setdefault("JAX_PLATFORMS", cfg.jax_platforms)
     from repro.coord.protocol import Connection
     from repro.obs import trace as obs_trace
     from repro.proxy.service import ProxyService
+    from repro.runtime.env import enable_compile_cache
+
+    enable_compile_cache()
 
     listener = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
     listener.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)

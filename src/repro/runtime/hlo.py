@@ -143,11 +143,8 @@ class Roofline:
 
 
 def cost_of(compiled) -> dict:
-    """Normalize compiled.cost_analysis() across jax versions."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    return dict(ca)
+    """compiled.cost_analysis() as a plain dict."""
+    return dict(compiled.cost_analysis())
 
 
 def analyze(

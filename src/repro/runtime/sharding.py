@@ -30,21 +30,9 @@ from repro.utils.tree import flatten_with_paths, map_with_paths
 # ---------------------------------------------------------------------------
 
 def _current_mesh_names() -> tuple[str, ...] | None:
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return tuple(m.axis_names)
-    except Exception:
-        pass
-    try:
-        from jax._src.mesh import thread_resources
-
-        pm = thread_resources.env.physical_mesh
-        if not pm.empty:
-            return tuple(pm.axis_names)
-    except Exception:
-        pass
-    return None
+    """Axis names of the mesh entered with ``jax.set_mesh``, if any."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else tuple(m.axis_names)
 
 
 def _filter_axes(spec: tuple, names: tuple[str, ...]) -> tuple:

@@ -6,9 +6,15 @@ in/out shardings from the ShardingRules. Gradient accumulation runs as a
 ``lax.scan`` over microbatch slices with f32 accumulators; the per-
 microbatch reduce-scatter of grads overlaps the next microbatch's compute
 under XLA's latency-hiding scheduler (§Perf lever).
+
+Each step is traced under its own mesh's axis names, so the activation
+constraints inside the model hold wherever the caller calls it from: a
+caller needs no mesh context, and one-device work around the step (chunk
+digests of single shards, restore) runs outside any.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -47,6 +53,16 @@ def make_train_state_specs(model: Model, rules: ShardingRules, optimizer: Optimi
 
     o_spec = map_with_paths(spec_for_opt, opt_shape)
     return params_shape, opt_shape, p_spec, o_spec
+
+
+def _on_mesh(fn, mesh):
+    """``fn`` traced with ``mesh`` as the context mesh."""
+    @functools.wraps(fn)
+    def traced(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args)
+
+    return traced
 
 
 def _split_microbatches(batch: Any, n: int) -> Any:
@@ -120,7 +136,7 @@ def make_train_step(
         return {"params": new_params, "opt": new_opt, "step": step + 1}, metrics
 
     jitted = jax.jit(
-        step_fn,
+        _on_mesh(step_fn, mesh),
         in_shardings=(state_shardings, None),
         out_shardings=(state_shardings, None),
         donate_argnums=(0,) if donate else (),
@@ -135,7 +151,7 @@ def make_prefill_step(model: Model, rules: ShardingRules, cache_len: int):
     def fn(params, batch):
         return model.prefill(params, batch, cache_len)
 
-    return jax.jit(fn, in_shardings=(p_shard, None)), p_shard
+    return jax.jit(_on_mesh(fn, rules.mesh), in_shardings=(p_shard, None)), p_shard
 
 
 def make_decode_step(model: Model, rules: ShardingRules, *, donate_cache: bool = True):
@@ -174,7 +190,7 @@ def make_decode_step(model: Model, rules: ShardingRules, *, donate_cache: bool =
         return model.decode(params, cache, tokens)
 
     jitted = jax.jit(
-        fn,
+        _on_mesh(fn, mesh),
         in_shardings=(p_shard, None, None),
         donate_argnums=(1,) if donate_cache else (),
     )

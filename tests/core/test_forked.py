@@ -36,7 +36,7 @@ def test_async_save_restores_exactly(tmp_store, backend):
     r = ck.save_async(1, s)
     r.wait()
     assert r.error is None
-    restored, m = RestoreManager(tmp_store).restore(verify=True)
+    restored, m = RestoreManager(tmp_store).restore(verify="store")
     assert tree_equal(jax.tree.map(np.asarray, s), restored)
     ck.close()
 
@@ -68,7 +68,7 @@ def test_incremental_second_save_writes_less(tmp_store, backend):
     r2.wait()
     assert r2.chunks_reused > 0
     assert r2.chunks_written <= 3  # 1 dirty chunk + host step leaf
-    restored, _ = RestoreManager(tmp_store).restore(verify=True)
+    restored, _ = RestoreManager(tmp_store).restore(verify="store")
     assert tree_equal(jax.tree.map(np.asarray, s2), restored)
     ck.close()
 

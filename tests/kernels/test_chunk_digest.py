@@ -42,6 +42,19 @@ def test_pallas_interpret_matches_oracle(rng, shape, cb):
     assert np.array_equal(want, got)
 
 
+@pytest.mark.parametrize("dtype", [ml_dtypes.bfloat16, np.float16, np.int16, np.uint16])
+@pytest.mark.parametrize("shape,cb", [
+    ((2048,), 256), ((100_001,), 4096), ((7, 131), 512), ((2**19 + 5,), 1 << 20),
+])
+def test_pallas_interpret_16bit_matches_oracle(rng, dtype, shape, cb):
+    # the kernel pairs 16-bit halves into words itself; odd lengths leave
+    # a half-filled last word
+    x = _rand(rng, dtype, shape)
+    want = ref.chunk_digests_np(x, cb)
+    got = np.asarray(ops.chunk_digests(jnp.asarray(x), cb, use_pallas="interpret"))
+    assert np.array_equal(want, got)
+
+
 def test_digest_detects_single_byte_change(rng):
     x = rng.integers(0, 255, 8192).astype(np.uint8)
     d1 = ref.chunk_digests_np(x, 1024)
