@@ -235,15 +235,16 @@ def restore_leaf(
 ) -> Any:
     """Restore one leaf, optionally placing it with the given sharding."""
     asm = _LeafAssembler(store, lrec)
-    if sharding is None:
-        return asm.full()
     shape = asm.shape
 
     def cb(index: tuple) -> np.ndarray:
-        if not shape:
-            return asm.window([], [])
         start, stop = _normalize_index(index, shape)
-        return asm.window(start, stop)
+        n = int(np.prod([b - a for a, b in zip(start, stop)], dtype=np.int64))
+        with store.timings.measure("restore/assemble", bytes=n * asm.dtype.itemsize):
+            return asm.window(start, stop)
+
+    if sharding is None:
+        return cb((slice(None),) * len(shape))
 
     return jax.make_array_from_callback(shape, sharding, cb)
 

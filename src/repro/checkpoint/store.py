@@ -14,6 +14,7 @@ from collections import OrderedDict
 
 from repro.checkpoint.codecs import get_codec
 from repro.checkpoint.manifest import ChunkRecord, step_dir
+from repro.utils.timing import Timings
 
 
 def host_data_file(step: int, host: int) -> str:
@@ -22,8 +23,10 @@ def host_data_file(step: int, host: int) -> str:
 
 
 class ChunkStore:
-    def __init__(self, root: str, *, cache_chunks: int = 256):
+    def __init__(self, root: str, *, cache_chunks: int = 256,
+                 timings: Timings | None = None):
         self.root = root
+        self.timings = timings or Timings()
         os.makedirs(root, exist_ok=True)
         self._cache: OrderedDict[tuple, bytes] = OrderedDict()
         self._cache_max = cache_chunks
@@ -97,15 +100,17 @@ class ChunkStore:
             if key in self._cache:
                 self._cache.move_to_end(key)
                 return self._cache[key]
-        with open(os.path.join(self.root, rec.file), "rb") as f:
-            f.seek(rec.file_offset)
-            comp = f.read(rec.comp_len)
+        with self.timings.measure("store/read", bytes=rec.comp_len):
+            with open(os.path.join(self.root, rec.file), "rb") as f:
+                f.seek(rec.file_offset)
+                comp = f.read(rec.comp_len)
         if len(comp) != rec.comp_len:
             raise IOError(
                 f"short read for {rec.file}@{rec.file_offset}: "
                 f"{len(comp)} < {rec.comp_len}"
             )
-        raw = get_codec(rec.codec).decompress(comp)
+        with self.timings.measure("store/decode", bytes=rec.raw_len):
+            raw = get_codec(rec.codec).decompress(comp)
         if len(raw) != rec.raw_len:
             raise IOError(f"decompressed length mismatch for {rec.file}")
         with self._lock:

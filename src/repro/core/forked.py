@@ -749,7 +749,8 @@ class ForkedCheckpointer:
                     {job.prev.step} | referenced_steps(job.prev)
                 )
         try:
-            self.backend.submit(job)
+            with self.timings.measure("ckpt/submit"):
+                self.backend.submit(job)
         except BaseException as e:
             # never strand the claimed buffer or leave a result that can't
             # complete (close()/wait_all() would hang on it)

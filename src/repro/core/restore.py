@@ -74,7 +74,7 @@ class LazyLeaves:
 
     def _materialize(self, path: str) -> Any:
         lrec = self._manifest.leaves[path]
-        with self.timings.measure("restore/leaf"):
+        with self.timings.measure("restore/leaf", path=path):
             leaf = restore_leaf(
                 self._store, lrec, self._sharding_for(path, tuple(lrec.shape))
             )
@@ -218,15 +218,14 @@ class RestoreManager:
                 ),
                 manifest,
             )
+        sharding_for = sharding_for or (lambda p, s: None)
         with self.timings.measure("restore/eager"):
-            leaves = {
-                path: restore_leaf(
-                    self.store,
-                    lrec,
-                    (sharding_for or (lambda p, s: None))(path, tuple(lrec.shape)),
-                )
-                for path, lrec in manifest.leaves.items()
-            }
+            leaves = {}
+            for path, lrec in manifest.leaves.items():
+                with self.timings.measure("restore/leaf", path=path):
+                    leaves[path] = restore_leaf(
+                        self.store, lrec, sharding_for(path, tuple(lrec.shape))
+                    )
             state = skeleton_fill(manifest.skeleton, leaves)
         if verify == "device":
             with self.timings.measure("restore/verify_device"):

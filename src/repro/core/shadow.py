@@ -438,8 +438,7 @@ class ShadowStateManager:
                 stream.buffer = self._alloc_buffer(
                     stream.nbytes, (stream.path, stream.shard_ordinal)
                 )
-                host = np.ascontiguousarray(data).reshape(-1).view(np.uint8)
-                np.copyto(stream.buffer, host)
+                self._copy_all(data, stream)
                 stream.states = [ChunkState.CLEAN] * stream.n_chunks
                 stats.chunks_fetched = stream.n_chunks
                 stats.bytes_fetched = stream.nbytes
@@ -516,8 +515,7 @@ class ShadowStateManager:
             cb = self.chunk_bytes
             if len(changed) == stream.n_chunks:
                 # everything dirty (first sync / full update): one bulk copy
-                host = np.ascontiguousarray(data).reshape(-1).view(np.uint8)
-                np.copyto(stream.buffer, host)
+                self._copy_all(data, stream)
                 if dev_digests is not None:
                     stream.digests = list(dev_digests)
                 else:
@@ -535,7 +533,9 @@ class ShadowStateManager:
             fetch = self._make_chunk_fetcher(data, stream, changed)
             for i in changed:
                 lo, hi = i * cb, min(stream.nbytes, (i + 1) * cb)
-                stream.buffer[lo:hi] = fetch(i, lo, hi)
+                piece = fetch(i, lo, hi)
+                with self.timings.measure("shadow/copy", bytes=hi - lo):
+                    stream.buffer[lo:hi] = piece
                 stream.digests[i] = (
                     dev_digests[i] if dev_digests is not None
                     else chunk_digest_np(stream.buffer[lo:hi])
@@ -545,6 +545,14 @@ class ShadowStateManager:
                 stats.bytes_fetched += hi - lo
         stats.fetch_us += (time.perf_counter() - t_fetch) * 1e6
         return stats
+
+    def _copy_all(self, data: Any, stream: _ShardStream) -> None:
+        """The whole shard into its shadow buffer: the device-to-host
+        transfer (``shadow/d2h``), then the host copy (``shadow/copy``)."""
+        with self.timings.measure("shadow/d2h", bytes=stream.nbytes):
+            host = np.ascontiguousarray(data).reshape(-1).view(np.uint8)
+        with self.timings.measure("shadow/copy", bytes=stream.nbytes):
+            np.copyto(stream.buffer, host)
 
     def _make_chunk_fetcher(self, data: Any, stream: _ShardStream, changed: list[int]):
         """Per-chunk device->host fetch: only dirty bytes cross the wire.
@@ -563,11 +571,13 @@ class ShadowStateManager:
             flat = data.reshape(-1)
 
             def fetch(i: int, lo: int, hi: int) -> np.ndarray:
-                piece = jax.device_get(flat[lo // itemsize : -(-hi // itemsize)])
+                with self.timings.measure("shadow/d2h", bytes=hi - lo):
+                    piece = jax.device_get(flat[lo // itemsize : -(-hi // itemsize)])
                 return piece.reshape(-1).view(np.uint8)[: hi - lo]
 
             return fetch
-        host = np.ascontiguousarray(data).reshape(-1).view(np.uint8)
+        with self.timings.measure("shadow/d2h", bytes=stream.nbytes):
+            host = np.ascontiguousarray(data).reshape(-1).view(np.uint8)
         return lambda i, lo, hi: host[lo:hi]
 
     def _device_digests(self, data: Any, stream: _ShardStream) -> list[int]:

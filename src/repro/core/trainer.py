@@ -79,9 +79,9 @@ class CheckpointedTrainer:
             )
         self.train_step = train_step
         self.device_runner = device_runner
-        self.store = ChunkStore(store_root)
-        self.policy = policy or CheckpointPolicy(interval_steps=100)
         self.timings = timings or Timings()
+        self.store = ChunkStore(store_root, timings=self.timings)
+        self.policy = policy or CheckpointPolicy(interval_steps=100)
         self.device_capacity_bytes = (
             int(device_capacity_bytes) if device_capacity_bytes else None
         )
@@ -353,7 +353,8 @@ class CheckpointedTrainer:
         r = self.checkpointer.save_async(step, state, meta={"wall": time.time()})
         self.results.append(r)
         self.policy.notify_checkpointed(step)
-        self._gc()
+        with self.timings.measure("ckpt/gc"):
+            self._gc()
         return r
 
     def _gc(self) -> None:

@@ -155,6 +155,7 @@ def digest_words(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="chunk_digest",
     )(x)
     acc = jax.lax.bitcast_convert_type(acc, jnp.uint32).reshape(n_chunks, 2, TILE_WORDS)
     hi = jax.lax.reduce(

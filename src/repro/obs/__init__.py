@@ -34,6 +34,13 @@ The *live* half (streaming, while the run runs):
 Enable with ``--obs-dir`` on ``launch/train`` / ``launch/cluster`` (or
 ``CRUM_OBS_DIR`` in the environment, which is how child processes
 inherit it).
+
+The division of spans: the shards here hold the causal spans that cross
+processes (a round's phase 1 in the app, its persist in the fork child,
+the coordinator's commit), on the wall clock. Spans inside one process
+(``Timings.measure`` and ``repro.utils.timing.span``) go to the
+``jax.profiler`` trace, on the device trace's clock, whenever a profiler
+session runs.
 """
 from repro.obs import trace
 from repro.obs.metrics import REGISTRY

@@ -3,13 +3,38 @@
 The paper reports drain time, transfer time, blocking checkpoint time and
 total persist time separately (§4.2–4.5); every CRUM phase here is timed so
 benchmarks can reproduce those splits.
+
+This module is the one instrumentation point for spans inside a process:
+every :meth:`Timings.measure` is also a span of the same name in the
+``jax.profiler`` trace (on the device trace's clock), and :func:`span` is
+that span alone, for code that holds no ``Timings``. Spans are recorded
+only while a profiler session runs. Causal spans that cross processes
+(fork-persist child, proxy, cluster workers) go to ``repro.obs.trace``'s
+JSONL shards instead.
 """
 from __future__ import annotations
 
+import sys
 import time
 from collections import defaultdict
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
+
+_OFF = nullcontext()
+
+
+def span(name: str, **args):
+    """A span ``name`` in the ``jax.profiler`` trace, with ``args`` (counts
+    such as ``bytes=``) attached to it.
+
+    Without a profiler session it costs a dictionary lookup and a flag
+    test. A process that has not imported JAX has no session, and this
+    imports nothing.
+    """
+    prof = sys.modules.get("jax._src.profiler")
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return _OFF
+    return prof.TraceAnnotation(name, **args)
 
 
 @dataclass
@@ -34,12 +59,15 @@ class Timings:
         }
 
     @contextmanager
-    def measure(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
+    def measure(self, name: str, **args):
+        """Times the block under ``name``; it is also :func:`span` ``name``
+        with ``args``."""
+        with span(name, **args):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.add(name, time.perf_counter() - t0)
 
 
 class Timer:
