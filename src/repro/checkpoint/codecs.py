@@ -16,6 +16,11 @@ Reproduces the paper's Table 2/3 strategy axis:
 All codecs release the GIL inside compress/decompress, which is what makes
 the forked-checkpointing writer pool overlap with the train loop.
 
+Restore decodes a chunk straight into its byte range of the leaf being
+assembled with :meth:`Codec.decompress_into`. The zstd codecs stream the
+frame into that range; every other codec, and any registered without a
+``decode_into``, decompresses the chunk and copies it there.
+
 ``zstandard`` is an *optional* dependency (the ``[zstd]`` extra): when it is
 absent the zstd codecs are simply not registered, and asking for one raises
 an error naming the missing package instead of breaking import of this
@@ -38,9 +43,28 @@ except ImportError:  # optional dependency — zstd codecs not registered
 
 @dataclass(frozen=True)
 class Codec:
+    """A named compress/decompress pair. ``decode_into(data, out)``, where
+    given, decodes ``data`` into ``out`` with no intermediate buffer and
+    returns what :meth:`decompress_into` returns."""
+
     name: str
     compress: Callable[[bytes], bytes]
     decompress: Callable[[bytes], bytes]
+    decode_into: Callable[[bytes, memoryview], int] | None = None
+
+    def decompress_into(self, data: bytes, out: memoryview) -> int:
+        """Decompress ``data`` into the front of the byte view ``out``.
+
+        Writes at most ``len(out)`` bytes and returns the length the frame
+        decodes to: less than ``len(out)`` for a short frame, more for one
+        that runs past ``out`` (nothing is written past its end).
+        """
+        if self.decode_into is not None:
+            return self.decode_into(data, out)
+        raw = self.decompress(data)
+        n = min(len(raw), len(out))
+        out[:n] = memoryview(raw)[:n]
+        return len(raw)
 
 
 def _zstd_c(level: int) -> Callable[[bytes], bytes]:
@@ -52,6 +76,18 @@ def _zstd_c(level: int) -> Callable[[bytes], bytes]:
 
 def _zstd_d(data: bytes) -> bytes:
     return zstandard.ZstdDecompressor().decompress(data)
+
+
+def _zstd_d_into(data: bytes, out: memoryview) -> int:
+    # a decompressor per call: restore's reader threads never share one
+    with zstandard.ZstdDecompressor().stream_reader(data) as reader:
+        n = 0
+        while n < len(out):
+            got = reader.readinto(out[n:])
+            if not got:
+                return n
+            n += got
+        return n + len(reader.read(1))  # a frame that goes on is too long
 
 
 _PGZIP_BLOCK = 1 << 20  # 1 MiB sub-blocks, one per worker task
@@ -112,8 +148,8 @@ _CODECS: dict[str, Codec] = {
 _MISSING: dict[str, tuple[str, str]] = {}
 
 if zstandard is not None:
-    _CODECS["zstd1"] = Codec("zstd1", _zstd_c(1), _zstd_d)
-    _CODECS["zstd9"] = Codec("zstd9", _zstd_c(9), _zstd_d)
+    _CODECS["zstd1"] = Codec("zstd1", _zstd_c(1), _zstd_d, _zstd_d_into)
+    _CODECS["zstd9"] = Codec("zstd9", _zstd_c(9), _zstd_d, _zstd_d_into)
 else:
     _MISSING["zstd1"] = ("zstandard", "zstd")
     _MISSING["zstd9"] = ("zstandard", "zstd")

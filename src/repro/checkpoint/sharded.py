@@ -173,7 +173,15 @@ def save_pytree(
 # --------------------------------------------------------------------------
 
 class _LeafAssembler:
-    """Assembles arbitrary index-windows of one stored leaf."""
+    """Assembles arbitrary index-windows of one stored leaf.
+
+    Each stored shard is decoded once per assembler, chunk by chunk
+    straight into one array. A window that is exactly a stored shard gets
+    that array itself the first time it is asked for (the single-device
+    restore, host leaves, a same-topology mesh restore); every other window
+    (elastic, over several shards or part of one, a replica asked for
+    again) is copied out of the shards it overlaps.
+    """
 
     def __init__(self, store: ChunkStore, lrec: LeafRecord):
         self.store = store
@@ -183,20 +191,33 @@ class _LeafAssembler:
         self._shard_cache: dict[int, np.ndarray] = {}
 
     def _shard_array(self, i: int) -> np.ndarray:
-        if i not in self._shard_cache:
-            s = self.lrec.shards[i]
-            raw = b"".join(self.store.read_chunk(c) for c in s.chunks)
-            shp = tuple(b - a for a, b in zip(s.start, s.stop))
-            n = int(np.prod(shp, dtype=np.int64)) if shp else 1
-            arr = np.frombuffer(raw, dtype=self.dtype, count=n).reshape(shp)
-            self._shard_cache[i] = arr
-        return self._shard_cache[i]
+        """Stored shard ``i``, each chunk decoded into its bytes of one array."""
+        if i in self._shard_cache:
+            return self._shard_cache[i]
+        s = self.lrec.shards[i]
+        out = np.empty(tuple(b - a for a, b in zip(s.start, s.stop)), dtype=self.dtype)
+        buf = memoryview(out.reshape(-1).view(np.uint8))
+        held = sum(c.raw_len for c in s.chunks)
+        if held != len(buf):
+            raise IOError(
+                f"chunks of {self.lrec.path} shard {s.start}:{s.stop} hold "
+                f"{held} bytes, not {len(buf)}"
+            )
+        off = 0
+        for c in s.chunks:
+            self.store.read_chunk_into(c, buf[off : off + c.raw_len])
+            off += c.raw_len
+        self._shard_cache[i] = out
+        return out
 
     def window(self, start: list[int], stop: list[int]) -> np.ndarray:
         """Assemble the [start, stop) window from overlapping stored shards."""
+        for i, s in enumerate(self.lrec.shards):
+            if (list(s.start), list(s.stop)) == (list(start), list(stop)):
+                if i not in self._shard_cache:
+                    return self._shard_array(i)
+                break
         out_shape = tuple(b - a for a, b in zip(start, stop))
-        if not out_shape:  # 0-d leaf
-            return self._shard_array(0).copy()
         out = np.empty(out_shape, dtype=self.dtype)
         filled = 0
         for i, s in enumerate(self.lrec.shards):

@@ -3,8 +3,12 @@
 Each host appends its compressed chunks to a single ``data-h<host>.bin``
 per checkpoint step (one sequential stream per host: the I/O pattern the
 paper's forked child produces). Reads are random-access by (file, offset,
-comp_len) from the manifest, with a small decompression cache so elastic
-restore does not decompress a chunk once per overlapping target shard.
+comp_len) from the manifest. ``read_chunk_into`` decodes a chunk straight
+into the caller's buffer (restore decodes every stored shard so) and keeps
+a read-only view of it, not a copy, in a cache bounded by the bytes of the
+arrays those views keep alive; a hit is copied out and used only if the
+copy still digests to the manifest's digest, since the array's owner may
+have written to it. ``read_chunk`` decodes into bytes of its own, uncached.
 """
 from __future__ import annotations
 
@@ -12,9 +16,20 @@ import os
 import threading
 from collections import OrderedDict
 
+import numpy as np
+
+from repro.checkpoint.chunking import chunk_digest_np
 from repro.checkpoint.codecs import get_codec
 from repro.checkpoint.manifest import ChunkRecord, step_dir
 from repro.utils.timing import Timings
+
+
+def _held_bytes(view: memoryview) -> int:
+    """Bytes a view keeps alive: the whole buffer it was cut from."""
+    base = view.obj
+    while getattr(base, "base", None) is not None:
+        base = base.base
+    return base.nbytes if isinstance(base, np.ndarray) else memoryview(base).nbytes
 
 
 def host_data_file(step: int, host: int) -> str:
@@ -23,16 +38,20 @@ def host_data_file(step: int, host: int) -> str:
 
 
 class ChunkStore:
-    def __init__(self, root: str, *, cache_chunks: int = 256,
+    def __init__(self, root: str, *, cache_bytes: int = 256 << 20,
                  timings: Timings | None = None):
         self.root = root
         self.timings = timings or Timings()
         os.makedirs(root, exist_ok=True)
-        self._cache: OrderedDict[tuple, bytes] = OrderedDict()
-        self._cache_max = cache_chunks
+        # read-only views of in-place decodes, each charged the bytes of the
+        # whole array it keeps alive; the charges sum to at most cache_bytes
+        self._cache: OrderedDict[tuple, tuple[memoryview, int]] = OrderedDict()
+        self._cache_bytes = cache_bytes
+        self._cache_held = 0
         self._lock = threading.Lock()
         self.bytes_read = 0
-        self.chunks_read = 0
+        self.chunks_read = 0  # chunks decoded, in place or not
+        self.chunks_in_place = 0  # of those, decoded by read_chunk_into
 
     # -- write path ---------------------------------------------------------
     class Writer:
@@ -94,12 +113,7 @@ class ChunkStore:
         return ChunkStore.Writer(self, step, host, lazy=lazy)
 
     # -- read path ------------------------------------------------------------
-    def read_chunk(self, rec: ChunkRecord) -> bytes:
-        key = (rec.file, rec.file_offset, rec.comp_len)
-        with self._lock:
-            if key in self._cache:
-                self._cache.move_to_end(key)
-                return self._cache[key]
+    def _read_payload(self, rec: ChunkRecord) -> bytes:
         with self.timings.measure("store/read", bytes=rec.comp_len):
             with open(os.path.join(self.root, rec.file), "rb") as f:
                 f.seek(rec.file_offset)
@@ -109,17 +123,73 @@ class ChunkStore:
                 f"short read for {rec.file}@{rec.file_offset}: "
                 f"{len(comp)} < {rec.comp_len}"
             )
+        return comp
+
+    def _count(self, rec: ChunkRecord, *, in_place: bool) -> None:
+        with self._lock:
+            self.bytes_read += rec.raw_len
+            self.chunks_read += 1
+            self.chunks_in_place += in_place
+
+    def read_chunk(self, rec: ChunkRecord) -> bytes:
+        """Read and decode one chunk into bytes of its own (no cache)."""
+        comp = self._read_payload(rec)
         with self.timings.measure("store/decode", bytes=rec.raw_len):
             raw = get_codec(rec.codec).decompress(comp)
         if len(raw) != rec.raw_len:
             raise IOError(f"decompressed length mismatch for {rec.file}")
-        with self._lock:
-            self.bytes_read += len(raw)
-            self.chunks_read += 1
-            self._cache[key] = raw
-            while len(self._cache) > self._cache_max:
-                self._cache.popitem(last=False)
+        self._count(rec, in_place=False)
         return raw
+
+    def read_chunk_into(self, rec: ChunkRecord, out: memoryview) -> None:
+        """Decode one chunk into ``out``, a byte view of ``rec.raw_len``
+        bytes, with no intermediate buffer (a cache hit is copied there)."""
+        if len(out) != rec.raw_len:
+            raise ValueError(f"{len(out)}-byte buffer for a {rec.raw_len}-byte chunk")
+        if self._copy_cached(rec, out):
+            return
+        comp = self._read_payload(rec)
+        with self.timings.measure("store/decode", bytes=rec.raw_len):
+            n = get_codec(rec.codec).decompress_into(comp, out)
+        if n != rec.raw_len:
+            raise IOError(
+                f"decompressed length mismatch for {rec.file}@{rec.file_offset}: "
+                f"{n} != {rec.raw_len}"
+            )
+        self._count(rec, in_place=True)
+        self._keep(rec, out.toreadonly())
+
+    # -- cache of in-place decodes ----------------------------------------------
+    def _keep(self, rec: ChunkRecord, view: memoryview) -> None:
+        held = _held_bytes(view)
+        if held > self._cache_bytes:
+            return
+        key = (rec.file, rec.file_offset, rec.comp_len)
+        with self._lock:
+            old = self._cache.pop(key, None)
+            self._cache_held += held - (old[1] if old else 0)
+            self._cache[key] = (view, held)
+            while self._cache_held > self._cache_bytes:
+                self._cache_held -= self._cache.popitem(last=False)[1][1]
+
+    def _copy_cached(self, rec: ChunkRecord, out: memoryview) -> bool:
+        """Copy a kept decode of ``rec`` into ``out``; False if there is none,
+        or if what was copied no longer digests to the manifest's digest (the
+        array it was decoded into has been written to since)."""
+        key = (rec.file, rec.file_offset, rec.comp_len)
+        with self._lock:
+            entry = self._cache.get(key)
+            if entry is None:
+                return False
+            self._cache.move_to_end(key)
+        out[:] = entry[0]
+        if chunk_digest_np(np.frombuffer(out, np.uint8)) == rec.digest:
+            return True
+        with self._lock:
+            if self._cache.get(key) is entry:
+                del self._cache[key]
+                self._cache_held -= entry[1]
+        return False
 
     # -- garbage collection ----------------------------------------------------
     def gc(self, keep_steps: list[int], *, pin_referenced: bool = True) -> list[int]:

@@ -204,3 +204,25 @@ def test_restore_elastic_unknown_step_raises(tmp_path):
     os.makedirs(root)
     with pytest.raises(FileNotFoundError):
         RestoreManager(ChunkStore(root)).restore_elastic(n_hosts=2, host=0)
+
+
+@pytest.mark.parametrize("n_old,n_new", [(2, 3), (2, 2)])
+def test_reshard_decodes_each_chunk_once_in_place(tmp_path, n_old, n_new):
+    """Every stored shard is decoded into an array of its own; a window that
+    is not one stored shard copies from those, and a shard another host's
+    window overlaps too comes from the store's cache of those decodes."""
+    root = str(tmp_path / "ck")
+    state = _state(rows=12)
+    cks = _commit_over_hosts(root, state, 1, n_old)
+    rm = RestoreManager(ChunkStore(root))
+    trees = [rm.restore_elastic(n_hosts=n_new, host=h)[0] for h in range(n_new)]
+    merged = _reassemble(trees)
+    flat, _ = flatten_with_paths(state)
+    for path, leaf in flat.items():
+        assert merged[path].dtype == np.asarray(leaf).dtype, path
+        assert merged[path].tobytes() == np.asarray(leaf).tobytes(), path
+    manifest = rm._pick_manifest(None)
+    n_chunks = sum(len(s.chunks) for lv in manifest.leaves.values() for s in lv.shards)
+    assert rm.store.chunks_in_place == rm.store.chunks_read == n_chunks
+    for ck in cks.values():
+        ck.close()
