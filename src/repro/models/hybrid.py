@@ -9,6 +9,10 @@ Decode carries: per-layer SSM states (stacked L) + a KV cache per shared-
 block *application* (n_apps = L // attn_every), indexed by an application
 counter that only advances inside the cond's true branch.
 
+The residual stream is carried in float32 (mamba_ssm's
+``residual_in_fp32``); each norm's output, which feeds a mixer or the
+head, is cast to the parameters' dtype.
+
 Deviation noted in DESIGN §6: Zamba2 concatenates the block input with the
 original embeddings before the shared block and applies per-invocation
 LoRA deltas; we apply the shared block to the residual stream directly.
@@ -76,7 +80,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
 def _shared_block(cfg: ModelConfig, shared: dict, x: jax.Array, positions):
     from repro.runtime.sharding import constrain
 
-    h = rmsnorm(x, shared["ln1"], cfg.norm_eps)
+    dtype = shared["attn"]["wo"].dtype
+    h = rmsnorm(x, shared["ln1"], cfg.norm_eps).astype(dtype)
     q, k, v = _qkv(cfg, shared["attn"], h)
     # pin head sharding: propagation through the reshape chose replication
     fm = "model" if cfg.tensor_parallel else None
@@ -93,7 +98,7 @@ def _shared_block(cfg: ModelConfig, shared: dict, x: jax.Array, positions):
     B, S = x.shape[0], x.shape[1]
     a = a.transpose(0, 2, 1, 3).reshape(B, S, cfg.q_dim) @ shared["attn"]["wo"]
     x = x + a
-    h2 = rmsnorm(x, shared["ln2"], cfg.norm_eps)
+    h2 = rmsnorm(x, shared["ln2"], cfg.norm_eps).astype(dtype)
     return x + mlp_apply(shared["mlp"], h2, cfg.mlp_type)
 
 
@@ -111,7 +116,8 @@ def hidden_forward(cfg: ModelConfig, params: dict, tokens: jax.Array):
     from repro.runtime.sharding import constrain
 
     B, S = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0)
+    dtype = params["embed"].dtype
+    x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
     positions = jnp.arange(S)
     shared = params.get("shared")
     apps = _app_layers(cfg) if shared is not None else []
@@ -121,7 +127,7 @@ def hidden_forward(cfg: ModelConfig, params: dict, tokens: jax.Array):
 
         def body(x, block):
             x = constrain(x, (cfg.batch_axes, None, None))
-            h = rmsnorm(x, block["ln"], cfg.norm_eps)
+            h = rmsnorm(x, block["ln"], cfg.norm_eps).astype(dtype)
             y, _ = ssd_forward(cfg, block["ssm"], h)
             return x + y, None
 
@@ -141,7 +147,7 @@ def hidden_forward(cfg: ModelConfig, params: dict, tokens: jax.Array):
         prev = a + 1
     if prev < cfg.num_layers:
         x = mamba_span(x, prev, cfg.num_layers)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps).astype(dtype)
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -171,17 +177,17 @@ def prefill(cfg: ModelConfig, params: dict, tokens: jax.Array, cache_len: int):
     Returns (last-token logits (B, 1, V), cache).
     """
     B, S = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0)
+    dtype = params["embed"].dtype
+    x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
     positions = jnp.arange(S)
     shared = params.get("shared")
     apps = _app_layers(cfg)
-    dtype = x.dtype
 
     def mamba_span(x, lo, hi):
         span = jax.tree.map(lambda p: p[lo:hi], params["blocks"])
 
         def body(x, block):
-            h = rmsnorm(x, block["ln"], cfg.norm_eps)
+            h = rmsnorm(x, block["ln"], cfg.norm_eps).astype(dtype)
             y, st = ssd_forward(cfg, block["ssm"], h)
             return x + y, st
 
@@ -192,7 +198,7 @@ def prefill(cfg: ModelConfig, params: dict, tokens: jax.Array, cache_len: int):
     for a in apps:
         x, st = mamba_span(x, prev, a + 1)
         ssm_states.append(st)
-        h = rmsnorm(x, shared["ln1"], cfg.norm_eps)
+        h = rmsnorm(x, shared["ln1"], cfg.norm_eps).astype(dtype)
         q, k, v = _qkv(cfg, shared["attn"], h)
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
@@ -203,7 +209,7 @@ def prefill(cfg: ModelConfig, params: dict, tokens: jax.Array, cache_len: int):
         )
         att = att.transpose(0, 2, 1, 3).reshape(B, S, cfg.q_dim) @ shared["attn"]["wo"]
         x = x + att
-        h2 = rmsnorm(x, shared["ln2"], cfg.norm_eps)
+        h2 = rmsnorm(x, shared["ln2"], cfg.norm_eps).astype(dtype)
         x = x + mlp_apply(shared["mlp"], h2, cfg.mlp_type)
         pad = [(0, 0), (0, 0), (0, cache_len - S), (0, 0)]
         k_caches.append(jnp.pad(k.astype(dtype), pad))
@@ -214,7 +220,7 @@ def prefill(cfg: ModelConfig, params: dict, tokens: jax.Array, cache_len: int):
         ssm_states.append(st)
 
     ssm = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *ssm_states)
-    x = rmsnorm(x[:, -1:, :], params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x[:, -1:, :], params["final_norm"], cfg.norm_eps).astype(dtype)
     logits = logits_from_embed(params["embed"], x)
     cache = {"ssm": ssm, "pos": jnp.asarray(S, jnp.int32)}
     if apps:
@@ -247,18 +253,19 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens: jax.Array):
     """tokens (B,) -> (logits (B, V) f32, new cache)."""
     B = tokens.shape[0]
     pos = cache["pos"]
-    x = jnp.take(params["embed"], tokens, axis=0)[:, None, :]  # (B, 1, D)
+    dtype = params["embed"].dtype
+    x = jnp.take(params["embed"], tokens, axis=0)[:, None, :].astype(jnp.float32)  # (B, 1, D)
     positions = pos[None]
     shared = params.get("shared")
     every = cfg.attn_every
 
     has_attn = shared is not None and n_shared_apps(cfg) > 0
-    kc = cache.get("k", jnp.zeros((1, B, 1, 1, 1), x.dtype))
-    vc = cache.get("v", jnp.zeros((1, B, 1, 1, 1), x.dtype))
+    kc = cache.get("k", jnp.zeros((1, B, 1, 1, 1), dtype))
+    vc = cache.get("v", jnp.zeros((1, B, 1, 1, 1), dtype))
 
     def shared_branch(args):
         x, app_idx, kc, vc = args
-        h = rmsnorm(x, shared["ln1"], cfg.norm_eps)
+        h = rmsnorm(x, shared["ln1"], cfg.norm_eps).astype(dtype)
         q, k, v = _qkv(cfg, shared["attn"], h)
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
@@ -271,14 +278,14 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens: jax.Array):
         a = decode_attention(q, k_app, v_app, pos)
         a = a.transpose(0, 2, 1, 3).reshape(B, 1, cfg.q_dim) @ shared["attn"]["wo"]
         x = x + a
-        h2 = rmsnorm(x, shared["ln2"], cfg.norm_eps)
+        h2 = rmsnorm(x, shared["ln2"], cfg.norm_eps).astype(dtype)
         x = x + mlp_apply(shared["mlp"], h2, cfg.mlp_type)
         return x, app_idx + 1, kc, vc
 
     def body(carry, scanned):
         x, app_idx, kc, vc = carry
         block, ssm_state, idx = scanned
-        h = rmsnorm(x, block["ln"], cfg.norm_eps)
+        h = rmsnorm(x, block["ln"], cfg.norm_eps).astype(dtype)
         y, ssm_new = ssm_decode_step(cfg, block["ssm"], ssm_state, h)
         x = x + y
         if has_attn:
@@ -295,7 +302,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens: jax.Array):
         (x, jnp.asarray(0, jnp.int32), kc, vc),
         (params["blocks"], cache["ssm"], jnp.arange(cfg.num_layers)),
     )
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps).astype(dtype)
     logits = logits_from_embed(params["embed"], x)[:, 0]
     new_cache = {"ssm": ssm_states, "pos": pos + 1}
     if has_attn:

@@ -96,13 +96,10 @@ def ssd_forward(cfg: ModelConfig, p: dict, x_in: jax.Array):
     sequence ended (asserted by tests/models/test_mamba2_ssd.py).
     """
     B, S, D = x_in.shape
-    DI, N, H, P = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
     Q = min(cfg.ssm_chunk, S)
     if S % Q:
         raise ValueError(f"S={S} not divisible by ssm_chunk={Q}")
-    nc = S // Q
     W = cfg.ssm_conv
-    ba = cfg.batch_axes
 
     z, xr, B_, C_, dt = _project(cfg, p, x_in)
     # conv state: the last W-1 *pre-conv* rows per segment (decode continues)
@@ -117,7 +114,23 @@ def ssd_forward(cfg: ModelConfig, p: dict, x_in: jax.Array):
     B_ = _causal_conv(B_, p["conv_B"], p["conv_Bb"])
     C_ = _causal_conv(C_, p["conv_C"], p["conv_Cb"])
 
+    # the scope names the SSD's device operations, forward and backward
+    with jax.named_scope("ssd"):
+        y, h_final = _ssd_core(cfg, p, xr, B_, C_, dt)
+    y = y.reshape(B, S, cfg.ssm_d_inner).astype(x_in.dtype)
+    y = constrain(y, (cfg.batch_axes, None, _feature_model_axis(cfg)))
+    y = gated_rmsnorm(y, z, p["norm_w"], cfg.norm_eps)
+    return y @ p["w_out"], {"h": h_final, "conv": conv_tail}
+
+
+def _ssd_core(cfg: ModelConfig, p: dict, xr, B_, C_, dt):
+    """The SSD on the conv outputs, in f32: (y (B, S, H, P), final state)."""
+    B, S, _ = xr.shape
+    N, H, P = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+    Q = min(cfg.ssm_chunk, S)
+    nc = S // Q
     # f32 SSD quantities; heads shard over model (H % model == 0 on zamba2)
+    ba = cfg.batch_axes
     fm = _feature_model_axis(cfg)
     xh = xr.reshape(B, S, H, P).astype(jnp.float32)
     xh = constrain(xh, (ba, None, fm, None))
@@ -140,11 +153,14 @@ def ssd_forward(cfg: ModelConfig, p: dict, x_in: jax.Array):
 
     # intra-chunk (quadratic, masked decay kernel)
     #   G[t, s] = (C_t . B_s) * exp(seg_t - seg_s) * dt_s   for s <= t
+    # masked before the exponential: for s > t the exponent is positive and
+    # overflows past a log-decay span of ~88, and inf times the zero
+    # cotangent of a masked entry is NaN in the backward pass
     CB = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)        # (B, nc, Q, Q)
-    decay = jnp.exp(seg[:, :, :, None, :] - seg[:, :, None, :, :])  # (B,nc,Q,Q,H)
-    mask = jnp.tril(jnp.ones((Q, Q), bool))
+    mask = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None]
+    gap = seg[:, :, :, None, :] - seg[:, :, None, :, :]
+    decay = jnp.exp(jnp.where(mask, gap, -jnp.inf))   # (B,nc,Q,Q,H)
     G = CB[..., None] * decay * dtc[:, :, None, :, :]
-    G = jnp.where(mask[None, None, :, :, None], G, 0.0)
     G = constrain(G, (ba, None, None, None, fm))
     y_intra = jnp.einsum("bcijh,bcjhp->bcihp", G, xc)
 
@@ -172,11 +188,7 @@ def ssd_forward(cfg: ModelConfig, p: dict, x_in: jax.Array):
     )
 
     y = (y_intra + y_inter).reshape(B, S, H, P)
-    y = y + xh * p["D"][None, None, :, None]
-    y = y.reshape(B, S, DI).astype(x_in.dtype)
-    y = constrain(y, (ba, None, fm))
-    y = gated_rmsnorm(y, z, p["norm_w"], cfg.norm_eps)
-    return y @ p["w_out"], {"h": h_final, "conv": conv_tail}
+    return y + xh * p["D"][None, None, :, None], h_final
 
 
 def ssm_decode_step(cfg: ModelConfig, p: dict, state: dict, x_tok: jax.Array):
