@@ -176,7 +176,8 @@ class _LeafAssembler:
     """Assembles arbitrary index-windows of one stored leaf.
 
     Each stored shard is decoded once per assembler, chunk by chunk
-    straight into one array. A window that is exactly a stored shard gets
+    straight into one array (the decodes on threads of the store's, while
+    this thread reads). A window that is exactly a stored shard gets
     that array itself the first time it is asked for (the single-device
     restore, host leaves, a same-topology mesh restore); every other window
     (elastic, over several shards or part of one, a replica asked for
@@ -203,10 +204,7 @@ class _LeafAssembler:
                 f"chunks of {self.lrec.path} shard {s.start}:{s.stop} hold "
                 f"{held} bytes, not {len(buf)}"
             )
-        off = 0
-        for c in s.chunks:
-            self.store.read_chunk_into(c, buf[off : off + c.raw_len])
-            off += c.raw_len
+        self.store.read_chunks_into(s.chunks, buf)
         self._shard_cache[i] = out
         return out
 

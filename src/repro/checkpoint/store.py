@@ -4,17 +4,22 @@ Each host appends its compressed chunks to a single ``data-h<host>.bin``
 per checkpoint step (one sequential stream per host: the I/O pattern the
 paper's forked child produces). Reads are random-access by (file, offset,
 comp_len) from the manifest. ``read_chunk_into`` decodes a chunk straight
-into the caller's buffer (restore decodes every stored shard so) and keeps
-a read-only view of it, not a copy, in a cache bounded by the bytes of the
-arrays those views keep alive; a hit is copied out and used only if the
-copy still digests to the manifest's digest, since the array's owner may
-have written to it. ``read_chunk`` decodes into bytes of its own, uncached.
+into the caller's buffer and keeps a read-only view of it, not a copy, in a
+cache bounded by the bytes of the arrays those views keep alive; a hit is
+copied out and used only if the copy still digests to the manifest's
+digest, since the array's owner may have written to it.
+``read_chunks_into`` does the same for a run of chunks, with their decodes
+on threads of its own while the calling thread reads on (restore reads
+every stored shard so). ``read_chunk`` decodes into bytes of its own,
+uncached.
 """
 from __future__ import annotations
 
+import concurrent.futures as cf
 import os
 import threading
-from collections import OrderedDict
+import time
+from collections import OrderedDict, deque
 
 import numpy as np
 
@@ -37,6 +42,12 @@ def host_data_file(step: int, host: int) -> str:
     return os.path.join(f"step_{step:08d}", f"data-h{host:04d}.bin")
 
 
+# a run of chunks decodes on one thread for each CPU this process may run on,
+# at most this many, each at most _AHEAD chunks behind the reads
+_MAX_DECODERS = 8
+_AHEAD = 4
+
+
 class ChunkStore:
     def __init__(self, root: str, *, cache_bytes: int = 256 << 20,
                  timings: Timings | None = None):
@@ -51,7 +62,8 @@ class ChunkStore:
         self._lock = threading.Lock()
         self.bytes_read = 0
         self.chunks_read = 0  # chunks decoded, in place or not
-        self.chunks_in_place = 0  # of those, decoded by read_chunk_into
+        self.chunks_in_place = 0  # of those, decoded into the caller's buffer
+        self.decode_s = 0.0  # seconds of those in-place decodes, summed over threads
 
     # -- write path ---------------------------------------------------------
     class Writer:
@@ -125,11 +137,12 @@ class ChunkStore:
             )
         return comp
 
-    def _count(self, rec: ChunkRecord, *, in_place: bool) -> None:
+    def _count(self, rec: ChunkRecord, *, in_place: bool, decode_s: float = 0.0) -> None:
         with self._lock:
             self.bytes_read += rec.raw_len
             self.chunks_read += 1
             self.chunks_in_place += in_place
+            self.decode_s += decode_s
 
     def read_chunk(self, rec: ChunkRecord) -> bytes:
         """Read and decode one chunk into bytes of its own (no cache)."""
@@ -150,13 +163,73 @@ class ChunkStore:
             return
         comp = self._read_payload(rec)
         with self.timings.measure("store/decode", bytes=rec.raw_len):
-            n = get_codec(rec.codec).decompress_into(comp, out)
+            seconds = self._decode_into(rec, comp, out)
+        self._decoded(rec, out, seconds)
+
+    def read_chunks_into(self, recs: list[ChunkRecord], out: memoryview) -> None:
+        """Decode ``recs`` into consecutive byte ranges of ``out``, as
+        :meth:`read_chunk_into` does each, with the decodes on threads.
+
+        This thread reads the chunks in order (``store/read``) and hands each
+        decode to a thread of a pool made for this call, at most ``_AHEAD``
+        chunks a thread ahead of the decodes; it then waits for each decode
+        in order, and that wait is the chunk's ``store/decode``. So the
+        store's timers still divide this thread's own time, and this thread
+        counts and keeps each chunk. The pool is shut down before this
+        returns or raises: a decode not started is cancelled, one started is
+        waited for, so no thread writes into ``out`` afterwards, and none is
+        alive at the fork persist backend's next fork.
+        """
+        views, off = [], 0
+        for rec in recs:
+            views.append(out[off : off + rec.raw_len])
+            off += rec.raw_len
+        if len(recs) < 2:
+            for rec, view in zip(recs, views):
+                self.read_chunk_into(rec, view)
+            return
+        workers = min(len(os.sched_getaffinity(0)), _MAX_DECODERS)
+        pool = cf.ThreadPoolExecutor(workers, thread_name_prefix="crum-decode")
+        pending: deque[tuple[ChunkRecord, memoryview, cf.Future]] = deque()
+
+        def wait_oldest() -> None:
+            rec, view, fut = pending.popleft()
+            with self.timings.measure("store/decode", bytes=rec.raw_len):
+                seconds = fut.result()
+            self._decoded(rec, view, seconds)
+
+        try:
+            for rec, view in zip(recs, views):
+                if len(pending) >= _AHEAD * workers:
+                    wait_oldest()
+                if self._copy_cached(rec, view):
+                    continue
+                comp = self._read_payload(rec)
+                pending.append((rec, view, pool.submit(self._decode_into, rec, comp, view)))
+            while pending:
+                wait_oldest()
+        finally:
+            pool.shutdown(wait=True, cancel_futures=True)
+
+    def _decode_into(self, rec: ChunkRecord, comp: bytes, out: memoryview) -> float:
+        """Decode ``comp``, the payload of ``rec``, into ``out``; returns the
+        seconds it took. Records no timer and touches no state of the store:
+        a decode thread of :meth:`read_chunks_into` runs it."""
+        t0 = time.perf_counter()
+        codec = get_codec(rec.codec)
+        try:
+            n = codec.decompress_into(comp, out)
+        except Exception as e:  # any codec's failure: name the chunk
+            raise IOError(f"cannot decode {rec.file}@{rec.file_offset}: {e!r}") from e
         if n != rec.raw_len:
             raise IOError(
                 f"decompressed length mismatch for {rec.file}@{rec.file_offset}: "
                 f"{n} != {rec.raw_len}"
             )
-        self._count(rec, in_place=True)
+        return time.perf_counter() - t0
+
+    def _decoded(self, rec: ChunkRecord, out: memoryview, decode_s: float) -> None:
+        self._count(rec, in_place=True, decode_s=decode_s)
         self._keep(rec, out.toreadonly())
 
     # -- cache of in-place decodes ----------------------------------------------
