@@ -11,9 +11,9 @@ meshes. Usage:
         --shape train_4k --mesh single
     PYTHONPATH=src python -m repro.launch.dryrun --all --out dryrun.jsonl
 
-Per cell it records compiled.memory_analysis() (fits-on-chip proof),
-cost_analysis() FLOPs/bytes, the parsed collective schedule, and the three
-roofline terms (runtime/hlo.py).
+Per cell it records the compile time and compiled.memory_analysis(): the
+per-device live bytes and whether they fit one chip's HBM. Speed is not
+estimated here; it is measured on the chip by bench/run.py.
 """
 import argparse
 import json
@@ -28,7 +28,6 @@ from repro.configs import get_config, list_archs
 from repro.launch.mesh import make_production_mesh
 from repro.models import SHAPES, build, shape_applicable
 from repro.optim import get_optimizer
-from repro.runtime import hlo
 from repro.runtime.sharding import ShardingRules
 from repro.runtime.steps import make_decode_step, make_prefill_step, make_train_step
 
@@ -40,6 +39,34 @@ def _tokens_of(cfg, shape_name: str) -> int:
     if info["kind"] == "train" or info["kind"] == "prefill":
         return info["seq_len"] * info["global_batch"]
     return info["global_batch"]  # decode: one token per sequence
+
+
+def memory_summary(compiled) -> dict:
+    """Per-device memory from compiled.memory_analysis() (best effort)."""
+    try:
+        ma = compiled.memory_analysis()
+    except Exception:
+        return {}
+    out = {}
+    for k in (
+        "argument_size_in_bytes",
+        "output_size_in_bytes",
+        "temp_size_in_bytes",
+        "alias_size_in_bytes",
+        "generated_code_size_in_bytes",
+    ):
+        v = getattr(ma, k, None)
+        if v is not None:
+            out[k] = int(v)
+    if out:
+        # live bytes: args + outputs + temps - aliased (donated) buffers
+        out["total_bytes"] = (
+            out.get("argument_size_in_bytes", 0)
+            + out.get("output_size_in_bytes", 0)
+            + out.get("temp_size_in_bytes", 0)
+            - out.get("alias_size_in_bytes", 0)
+        )
+    return out
 
 
 def run_cell(
@@ -95,9 +122,6 @@ def run_cell(
                 "step": jax.ShapeDtypeStruct((), np.int32),
             }
             lowered = jitted.lower(state_abs, specs)
-            n_flops = hlo.model_flops_train(
-                cfg.active_params_per_token(), _tokens_of(cfg, shape_name)
-            )
         elif kind == "prefill":
             jitted, p_sh = make_prefill_step(model, rules, info["seq_len"])
             specs = model.input_specs(shape_name)
@@ -109,9 +133,6 @@ def run_cell(
             )
             params_shape = jax.eval_shape(lambda: model.init(jax.random.key(0)))
             lowered = jitted.lower(params_shape, specs)
-            n_flops = hlo.model_flops_forward(
-                cfg.active_params_per_token(), _tokens_of(cfg, shape_name)
-            )
         else:  # decode
             jitted, p_sh, cache_sh_fn, tok_sh = make_decode_step(model, rules)
             specs = model.input_specs(shape_name)
@@ -119,27 +140,10 @@ def run_cell(
             tok_abs = _with_sh(specs["tokens"], tok_sh(specs["tokens"]))
             params_shape = jax.eval_shape(lambda: model.init(jax.random.key(0)))
             lowered = jitted.lower(params_shape, cache_abs, tok_abs)
-            n_flops = hlo.model_flops_forward(
-                cfg.active_params_per_token(), _tokens_of(cfg, shape_name)
-            )
 
         compiled = lowered.compile()
 
-    mem = hlo.memory_summary(compiled)
-    text = compiled.as_text()
-    # loop-aware HLO cost: trip-count-multiplied dots/collectives/bytes
-    # (cost_analysis() counts while bodies once — useless for scanned layers)
-    from repro.runtime.hlo_counter import loop_aware_cost
-
-    cost = loop_aware_cost(text)
-    roof = hlo.Roofline(
-        flops=cost.flops * chips,
-        hbm_bytes=cost.hbm_bytes * chips,
-        collective_bytes=cost.collective_bytes * chips,
-        chips=chips,
-        model_flops=n_flops,
-    ).finalize()
-    raw = hlo.cost_of(compiled)
+    mem = memory_summary(compiled)
     rec.update(
         status="ok",
         compile_s=round(time.perf_counter() - t0, 1),
@@ -150,13 +154,6 @@ def run_cell(
         memory=mem,
         per_device_bytes=mem.get("total_bytes"),
         fits_hbm=(mem.get("total_bytes", 0) <= HBM_PER_CHIP) if mem else None,
-        roofline=roof.as_dict(),
-        collectives={k: v * chips for k, v in cost.coll_by_kind.items()},
-        collective_counts=cost.coll_counts,
-        unknown_trip_loops=cost.unknown_trip_loops,
-        raw_cost_analysis={
-            "flops": raw.get("flops"), "bytes_accessed": raw.get("bytes accessed")
-        },
     )
     return rec
 

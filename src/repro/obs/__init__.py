@@ -27,9 +27,6 @@ The *live* half (streaming, while the run runs):
   divergence) emitting versioned ``alert`` journal records.
 * :mod:`repro.obs.top` — ``python -m repro.obs.top`` terminal dashboard
   over a live coordinator endpoint or a finished run dir.
-* :mod:`repro.obs.baseline` — diff fresh bench rows against the
-  committed ``BENCH_results.json`` (``benchmarks.run --compare``);
-  ``BENCH_history.jsonl`` keeps the trajectory in-repo.
 
 Enable with ``--obs-dir`` on ``launch/train`` / ``launch/cluster`` (or
 ``CRUM_OBS_DIR`` in the environment, which is how child processes

@@ -8,8 +8,8 @@ module is the *live* half:
   :class:`~repro.obs.metrics.Registry` counter delta since the last
   heartbeat and rides it on the HEARTBEAT frame the worker already
   sends. Zero extra syscalls: the payload travels inside the same
-  framed ``sendall`` as the heartbeat itself (``benchmarks/obs_overhead``
-  pins the collect cost; a unit test pins the one-frame property).
+  framed ``sendall`` as the heartbeat itself (a unit test pins the
+  one-frame property).
 * **Coordinator side** — :class:`LiveAggregator` folds those deltas into
   a bounded in-memory time-series store (:class:`SeriesStore`, one ring
   buffer per ``(host, metric)``), deduplicated by per-host sequence

@@ -31,10 +31,10 @@ METRICS_SCHEMA = "crum-metrics/1"
 
 # ---------------------------------------------------------------------------
 # Pinned public key sets. These names are consumed across layer boundaries —
-# by benchmarks/gate.py rows, RoundRecord, SYNCED info consumers and the
-# canonical registry mapping below. tests/obs/test_naming.py pins them;
-# changing a producer without updating the pin (and every consumer) is a
-# cross-layer break, which is exactly what the test is for.
+# by RoundRecord, SYNCED info consumers and the canonical registry mapping
+# below. tests/obs/test_naming.py pins them; changing a producer without
+# updating the pin (and every consumer) is a cross-layer break, which is
+# exactly what the test is for.
 # ---------------------------------------------------------------------------
 
 PAGING_STAT_KEYS = frozenset(
@@ -110,18 +110,6 @@ ROUND_RECORD_KEYS = frozenset(
         "digest_us",
         "fetch_us",
         "stall_us",
-    }
-)
-
-# Row fields benchmarks/gate.py reads from BENCH_results.json.
-GATE_ROW_KEYS = frozenset(
-    {
-        "overhead_pct",
-        "stall_ratio",
-        "boundary_scan_gone",
-        "bit_identical",
-        "boundary_bit_identical",
-        "us_per_call",
     }
 )
 

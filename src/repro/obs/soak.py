@@ -24,7 +24,10 @@ hard booleans:
     the merged trace passes ``repro.obs.critpath.check`` (orphan
     subtrees only where deaths are journaled),
 ``envelope_ok``
-    no committed round exceeded the duration envelope.
+    no committed round exceeded the duration envelope,
+``injected``
+    the schedule injected at least one fault (a soak that injected
+    nothing tested nothing).
 
 ``pass`` is the conjunction. Exit status follows it under ``--check``.
 """
@@ -255,6 +258,7 @@ def verdict(run_dir: str, *, round_envelope_s: float = 30.0,
         "leaks_flat": leaks_flat,
         "critpath_ok": critpath_ok,
         "envelope_ok": not slow,
+        "injected": bool(inj_rows),
     }
     return {
         "schema": SOAK_SCHEMA,

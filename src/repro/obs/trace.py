@@ -11,8 +11,8 @@ Design constraints, in order:
 1. **Disabled is free.** The module-global ``TRACER`` is ``None`` until
    :func:`enable` runs. Hot paths hoist ``tr = trace.get()`` and guard
    with ``if tr is not None`` — the disabled path is one global load and
-   one identity test, no allocation, no call. ``benchmarks/obs_overhead``
-   pins this.
+   one identity test, no allocation, no call. ``tests/obs/test_trace.py``
+   pins that it stays off until enabled.
 2. **SIGKILL-tolerant.** Each event is one line written with a single
    ``os.write`` on an ``O_APPEND`` fd: lines from concurrent writers
    never interleave, and a kill mid-write tears at most the final line

@@ -1,10 +1,10 @@
 """Cross-layer naming regression pins.
 
 The key sets frozen in ``repro.obs.metrics`` are consumed across layer
-boundaries — benchmarks/gate.py reads gate-row fields, RoundRecord's
-shape IS the journal ``round`` line, SYNCED info dicts cross the proxy
-control plane. A producer renaming a key without updating the pin (and
-every consumer) is a cross-layer break; these tests make it loud.
+boundaries — RoundRecord's shape IS the journal ``round`` line, SYNCED
+info dicts cross the proxy control plane. A producer renaming a key
+without updating the pin (and every consumer) is a cross-layer break;
+these tests make it loud.
 """
 import dataclasses
 
@@ -81,13 +81,3 @@ def test_sync_info_keys_pin():
     for key in m.SYNC_INFO_KEYS:
         assert f'"{key}"' in src, f"SYNCED info key {key!r} gone"
 
-
-def test_gate_row_keys_pin():
-    """benchmarks/gate.py reads exactly these row fields."""
-    import inspect
-
-    from benchmarks import gate
-
-    src = inspect.getsource(gate)
-    for key in m.GATE_ROW_KEYS:
-        assert f"{key}" in src, f"gate consumes {key!r} but pin says so"

@@ -128,6 +128,7 @@ def test_clean_run_passes(tmp_path):
         "leaks_flat": True,
         "critpath_ok": True,
         "envelope_ok": True,
+        "injected": True,
     }
     assert doc["pass"]
 
@@ -170,16 +171,18 @@ def test_envelope_and_leak_checks(tmp_path):
     assert doc["leak_growth"]["coord_fd"] == 30.0
 
 
-def test_gate_soak_clean():
-    from benchmarks.gate import soak_clean
-
-    good = {"schema": "crum-soak/1", "n_injections": 3,
-            "checks": {"a": True, "b": True}}
-    assert soak_clean(good) == []
-    bad = {"schema": "crum-soak/1", "n_injections": 3,
-           "checks": {"a": True, "no_unexplained_alerts": False}}
-    assert any("no_unexplained_alerts" in v for v in soak_clean(bad))
-    assert soak_clean({"schema": "nope"})
-    empty = {"schema": "crum-soak/1", "n_injections": 0,
-             "checks": {"a": True}}
-    assert any("zero injections" in v for v in soak_clean(empty))
+def test_gate_soak_clean(tmp_path):
+    """A soak that injected nothing tested nothing: it fails the verdict
+    even when every other check holds."""
+    run_dir = _write_run(
+        tmp_path,
+        [],
+        [{"event": "round", "t": T0, "step": 2, "status": "committed",
+          "round_s": 1.0}],
+    )
+    doc = verdict(run_dir)
+    assert doc["n_injections"] == 0
+    assert doc["checks"]["injected"] is False
+    assert all(ok for name, ok in doc["checks"].items()
+               if name != "injected"), doc["checks"]
+    assert doc["pass"] is False
